@@ -42,16 +42,22 @@ from .standardize import COSINE, L2, prepare
 PLAN_STAGES = ("search_stage",)
 
 
+# Full f32 products: a TPU runs a default-precision f32 matmul as one bf16
+# pass, which would train different centroids than any other platform.
+_HI = jax.lax.Precision.HIGHEST
+
+
 def _assign(x: jnp.ndarray, cents: jnp.ndarray, metric: str) -> jnp.ndarray:
     """Nearest centroid per row.  argmin/argmax are stable (lowest index)."""
+    xc = jnp.matmul(x, cents.T, precision=_HI)
     if metric == L2:
         d2 = (
             jnp.sum(x * x, axis=1, keepdims=True)
-            - 2.0 * x @ cents.T
+            - 2.0 * xc
             + jnp.sum(cents * cents, axis=1)[None, :]
         )
         return jnp.argmin(d2, axis=1)
-    return jnp.argmax(x @ cents.T, axis=1)
+    return jnp.argmax(xc, axis=1)
 
 
 @functools.partial(jax.jit, static_argnames=("n_clusters", "metric", "iters"))
@@ -61,7 +67,7 @@ def _kmeans(x: jnp.ndarray, init: jnp.ndarray, *, n_clusters: int, metric: str, 
     def step(cents, _):
         a = _assign(x, cents, metric)
         one_hot = jax.nn.one_hot(a, n_clusters, dtype=x.dtype)      # [n, k]
-        sums = one_hot.T @ x                                        # [k, d]
+        sums = jnp.matmul(one_hot.T, x, precision=_HI)              # [k, d]
         counts = jnp.sum(one_hot, axis=0)[:, None]                  # [k, 1]
         means = sums / jnp.maximum(counts, 1.0)
         new = jnp.where(counts > 0, means, cents)
@@ -113,13 +119,9 @@ def search_stage(
     O(nlist * max_cell) padded table — a skewed clustering costs padding
     proportional to the skew of the probed cells only.
     """
+    cs = jnp.matmul(q_rot, centroids.T, precision=_HI)
     if metric == L2:
-        cs = (
-            q_rot @ centroids.T
-            - 0.5 * jnp.sum(centroids * centroids, axis=1)[None, :]
-        )
-    else:
-        cs = q_rot @ centroids.T
+        cs = cs - 0.5 * jnp.sum(centroids * centroids, axis=1)[None, :]
     _, probe = topk(cs, nprobe)                           # [b, nprobe]
     lens = (offsets[1:] - offsets[:-1])[probe]            # [b, nprobe]
     cum = jnp.cumsum(lens, axis=1)                        # [b, nprobe]

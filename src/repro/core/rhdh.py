@@ -60,8 +60,14 @@ def rademacher_signs(seed: int, d_pad: int) -> jnp.ndarray:
     pins.  ensure_compile_time_eval forces the eager path, so the stage
     jaxpr sees only a ±1 constant — same bits, no random_* primitives
     (repro.analysis INV-NO-HOST-IN-TRACE).
+
+    The sign stream is part of the .mvec format: a file stores only the
+    seed.  JAX 0.5 switched threefry's default bit layout
+    (``jax_threefry_partitionable``), which would give every stored seed a
+    new diagonal; the layout every existing file was written with is pinned
+    here.
     """
-    with jax.ensure_compile_time_eval():
+    with jax.ensure_compile_time_eval(), jax.threefry_partitionable(False):
         key = jax.random.key(np.uint32(seed & 0xFFFFFFFF))
         key = jax.random.fold_in(key, np.uint32((seed >> 32) & 0xFFFFFFFF))
         return jax.random.rademacher(key, (d_pad,), dtype=jnp.float32)
@@ -80,8 +86,11 @@ def fwht(x: jnp.ndarray) -> jnp.ndarray:
     hb = jnp.asarray(hadamard_matrix(b))
     xr = x.reshape(x.shape[:-1] + (a, b))
     # H symmetric: H_a X H_b via two einsums (MXU-friendly contractions).
-    y = jnp.einsum("ij,...jk->...ik", ha, xr)
-    y = jnp.einsum("...ik,kl->...il", y, hb)
+    # HIGHEST: the TPU default runs an f32 matmul as one bf16 pass, which
+    # would move coordinates across Lloyd-Max boundaries and change codes.
+    hi = jax.lax.Precision.HIGHEST
+    y = jnp.einsum("ij,...jk->...ik", ha, xr, precision=hi)
+    y = jnp.einsum("...ik,kl->...il", y, hb, precision=hi)
     return y.reshape(x.shape)
 
 

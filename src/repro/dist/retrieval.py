@@ -32,7 +32,6 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import binary as bin_mod
@@ -175,11 +174,11 @@ def make_scan_topk_shardmap(
         if with_mask:
             in_specs.append(P(axes))
             operands.append(pad_rows(mask, n_pad, fill=False))
-        return shard_map(
+        return jax.shard_map(
             local_scan, mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(*operands)
 
     return call
@@ -252,11 +251,11 @@ def make_cascade_topk_shardmap(
         if with_mask:
             in_specs.append(P(axes))
             operands.append(pad_rows(mask, n_pad, fill=False))
-        return shard_map(
+        return jax.shard_map(
             local_scan, mesh=mesh,
             in_specs=tuple(in_specs),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(*operands)
 
     return call
@@ -290,11 +289,11 @@ def make_scan_topk_f32_shardmap(
             v, li = jax.lax.top_k(s, k_local)
             return _merge_topk(v, jnp.take(gids, li), axes, k)
 
-        return shard_map(
+        return jax.shard_map(
             local_scan, mesh=mesh,
             in_specs=(P(), P(axes, None)),
             out_specs=(P(), P()),
-            check_rep=False,
+            check_vma=False,
         )(queries, corpus_p)
 
     return call

@@ -47,10 +47,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .nibble_dot import GRID_PARAMS
+
 
 def _popcount8(x: jnp.ndarray) -> jnp.ndarray:
-    """SWAR popcount of a uint8 array (values 0..8) — the kernel-body form
-    (Mosaic-safe: shifts/ands/adds only)."""
+    """SWAR popcount (values 0..8) of byte values held in an int32 array —
+    the kernel-body form (Mosaic-safe: 32-bit shifts/ands/adds only)."""
     x = x - ((x >> 1) & 0x55)
     x = (x & 0x33) + ((x >> 2) & 0x33)
     return (x + (x >> 4)) & 0x0F
@@ -94,10 +96,11 @@ def _sign_hamming_kernel(cbits_ref, qbits_ref, out_ref):
     """One (bq, bn) int32 hamming tile, accumulating over packed-byte blocks."""
     k = pl.program_id(2)
 
-    cbits = cbits_ref[...]                          # [bn, bk] uint8
-    qbits = qbits_ref[...]                          # [bq, bk] uint8
+    # Widen before any bit op: Mosaic has no 8-bit vector XOR/AND/shift.
+    cbits = cbits_ref[...].astype(jnp.int32)        # [bn, bk] bytes 0..255
+    qbits = qbits_ref[...].astype(jnp.int32)        # [bq, bk]
     x = jnp.bitwise_xor(qbits[:, None, :], cbits[None, :, :])
-    part = jnp.sum(_popcount8(x).astype(jnp.int32), axis=-1)   # [bq, bn]
+    part = jnp.sum(_popcount8(x), axis=-1)          # [bq, bn]
 
     @pl.when(k == 0)
     def _init():
@@ -139,9 +142,7 @@ def sign_hamming_raw(
         ],
         out_specs=pl.BlockSpec((block_q, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.int32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=GRID_PARAMS,
         interpret=interpret,
     )(cbits, qbits)
 
@@ -197,11 +198,12 @@ def _crumb_cross_kernel(chi_ref, clo_ref, qhi_ref, qlo_ref, out_ref):
     """One (bq, bn) int32 tile of the pairwise term: four weighted
     AND+popcount passes over the plane bytes (zero pad bytes AND to 0)."""
     k = pl.program_id(2)
-    chi, clo = chi_ref[...], clo_ref[...]           # [bn, bk] uint8
-    qhi, qlo = qhi_ref[...], qlo_ref[...]           # [bq, bk] uint8
+    i32 = jnp.int32                                 # widened, as above
+    chi, clo = chi_ref[...].astype(i32), clo_ref[...].astype(i32)   # [bn, bk]
+    qhi, qlo = qhi_ref[...].astype(i32), qlo_ref[...].astype(i32)   # [bq, bk]
 
     def pc(a):
-        return jnp.sum(_popcount8(a).astype(jnp.int32), axis=-1)
+        return jnp.sum(_popcount8(a), axis=-1)
 
     part = (16 * pc(qhi[:, None, :] & chi[None, :, :])
             + 8 * pc(qhi[:, None, :] & clo[None, :, :])
@@ -252,9 +254,7 @@ def crumb_affinity_raw(
         in_specs=[corpus_spec, corpus_spec, query_spec, query_spec],
         out_specs=pl.BlockSpec((block_q, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.int32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=GRID_PARAMS,
         interpret=interpret,
     )(chi, clo, qhi, qlo)
     return cross + _crumb_corrections(chi, clo, qhi, qlo, dim)

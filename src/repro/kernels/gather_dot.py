@@ -36,7 +36,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from .nibble_dot import _TABLE2, _TABLE4, _dequant_select
+from .nibble_dot import F32_DOT, GRID_PARAMS, _TABLE2, _TABLE4, _dequant_select
 
 
 def _round_up(x: int, m: int) -> int:
@@ -62,22 +62,21 @@ def _nibble_tile(g: jnp.ndarray, q_even: jnp.ndarray, q_odd: jnp.ndarray) -> jnp
     Nibble 2i is the low half of byte i, nibble 2i+1 the high half, so
     ``deq(lo) @ q_even + deq(hi) @ q_odd`` is the exact dot product.
     """
-    lo = (g & 0xF).astype(jnp.int32)
-    hi = (g >> 4).astype(jnp.int32)
-    part = jnp.dot(_dequant_select(lo, _TABLE4), q_even,
-                   preferred_element_type=jnp.float32)
-    part += jnp.dot(_dequant_select(hi, _TABLE4), q_odd,
-                    preferred_element_type=jnp.float32)
+    g = g.astype(jnp.int32)     # Mosaic has no 8-bit vector shifts or masks
+    lo = g & 0xF
+    hi = g >> 4
+    part = jnp.dot(_dequant_select(lo, _TABLE4), q_even, **F32_DOT)
+    part += jnp.dot(_dequant_select(hi, _TABLE4), q_odd, **F32_DOT)
     return part
 
 
 def _crumb_tile(g: jnp.ndarray, q0, q1, q2, q3) -> jnp.ndarray:
     """2-bit variant: four crumbs per byte, four deinterleaved planes."""
+    g = g.astype(jnp.int32)
     part = jnp.zeros((g.shape[0],), jnp.float32)
     for shift, q in ((0, q0), (2, q1), (4, q2), (6, q3)):
-        codes = ((g >> shift) & 0x3).astype(jnp.int32)
-        part += jnp.dot(_dequant_select(codes, _TABLE2), q,
-                        preferred_element_type=jnp.float32)
+        codes = (g >> shift) & 0x3
+        part += jnp.dot(_dequant_select(codes, _TABLE2), q, **F32_DOT)
     return part
 
 
@@ -149,9 +148,7 @@ def gather_nibble_dot_raw(
         ],
         out_specs=pl.BlockSpec((block_b, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=GRID_PARAMS,
         interpret=interpret,
     )(gathered, q_even, q_odd)
 
@@ -184,9 +181,7 @@ def gather_crumb_dot_raw(
         ],
         out_specs=pl.BlockSpec((block_b, block_m), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, m), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=GRID_PARAMS,
         interpret=interpret,
     )(gathered, q_planes[0], q_planes[1], q_planes[2], q_planes[3])
 

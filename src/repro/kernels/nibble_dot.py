@@ -35,8 +35,21 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core import lloydmax
+
+# Every scan kernel in this package runs a (query-block, row-block,
+# packed-dim-block) grid: the first two axes are independent, the last one
+# accumulates into the resident output tile.
+GRID_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "arbitrary"))
+
+# Full f32 products on the MXU.  Mosaic's default runs an f32 dot as one
+# bf16 pass, which reorders near-tied neighbours against every other
+# platform's answer for the same codes.
+F32_DOT = dict(preferred_element_type=jnp.float32,
+               precision=jax.lax.Precision.HIGHEST)
 
 # The frozen Lloyd-Max tables, baked in as Python floats (immediates).
 # Shared with the gathered candidate-scan kernel (gather_dot.py) so every
@@ -63,17 +76,18 @@ def _nibble_dot_kernel(packed_ref, q_even_ref, q_odd_ref, out_ref, *, n_k: int):
     """One (bq, bn) output tile, accumulating over the packed-dim grid axis."""
     k = pl.program_id(2)
 
-    packed = packed_ref[...]                        # [bn, bk] uint8
-    lo = (packed & 0xF).astype(jnp.int32)           # nibble 2i   (dims 0,2,4,..)
-    hi = (packed >> 4).astype(jnp.int32)            # nibble 2i+1 (dims 1,3,5,..)
+    # Widen before any bit op: Mosaic has no 8-bit vector shifts or masks.
+    packed = packed_ref[...].astype(jnp.int32)      # [bn, bk] bytes 0..255
+    lo = packed & 0xF                               # nibble 2i   (dims 0,2,4,..)
+    hi = packed >> 4                                # nibble 2i+1 (dims 1,3,5,..)
     deq_lo = _dequant_select(lo, _TABLE4)           # [bn, bk] f32
     deq_hi = _dequant_select(hi, _TABLE4)
 
     q_even = q_even_ref[...]                        # [bq, bk] f32
     q_odd = q_odd_ref[...]
 
-    part = jnp.dot(q_even, deq_lo.T, preferred_element_type=jnp.float32)
-    part += jnp.dot(q_odd, deq_hi.T, preferred_element_type=jnp.float32)
+    part = jnp.dot(q_even, deq_lo.T, **F32_DOT)
+    part += jnp.dot(q_odd, deq_hi.T, **F32_DOT)
 
     @pl.when(k == 0)
     def _init():
@@ -120,9 +134,7 @@ def nibble_dot_raw(
         ],
         out_specs=pl.BlockSpec((block_q, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=GRID_PARAMS,
         interpret=interpret,
     )(packed, q_even, q_odd)
 
@@ -130,12 +142,12 @@ def nibble_dot_raw(
 def _crumb_dot_kernel(packed_ref, q0_ref, q1_ref, q2_ref, q3_ref, out_ref):
     """2-bit variant: four crumbs per byte, four deinterleaved query planes."""
     k = pl.program_id(2)
-    packed = packed_ref[...]
+    packed = packed_ref[...].astype(jnp.int32)      # widened, as above
     part = jnp.zeros((q0_ref.shape[0], packed.shape[0]), jnp.float32)
     for shift, q_ref in ((0, q0_ref), (2, q1_ref), (4, q2_ref), (6, q3_ref)):
-        codes = ((packed >> shift) & 0x3).astype(jnp.int32)
+        codes = (packed >> shift) & 0x3
         deq = _dequant_select(codes, _TABLE2)
-        part += jnp.dot(q_ref[...], deq.T, preferred_element_type=jnp.float32)
+        part += jnp.dot(q_ref[...], deq.T, **F32_DOT)
 
     @pl.when(k == 0)
     def _init():
@@ -176,8 +188,6 @@ def crumb_dot_raw(
         ],
         out_specs=pl.BlockSpec((block_q, block_n), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
-        compiler_params=dict(
-            mosaic=dict(dimension_semantics=("parallel", "parallel", "arbitrary"))
-        ) if not interpret else None,
+        compiler_params=GRID_PARAMS,
         interpret=interpret,
     )(packed, q_planes[0], q_planes[1], q_planes[2], q_planes[3])

@@ -77,6 +77,7 @@ import numpy as np
 from repro import engine, obs
 from repro.core import Eq, MonaVec, TenantRegistry
 from repro.data.synthetic import embedding_corpus, queries_from_corpus
+from repro.launch import compile_cache
 
 
 def main() -> None:
@@ -157,10 +158,12 @@ def main() -> None:
     if args.use_kernel == "on" and not args.interpret:
         import jax
         if jax.default_backend() != "tpu":
-            # resolve_dispatch will fill interpret=True off-TPU: say so
-            # instead of reporting per-grid-cell emulation QPS as kernel QPS.
-            print("[serve] WARNING: no TPU backend — forced kernel runs in "
-                  "interpret mode (validation speed, not production)")
+            # Off-TPU the forced kernel could only run in interpret mode;
+            # emulation QPS must never be reported as kernel QPS.
+            raise SystemExit(
+                f"--use-kernel on needs a TPU (backend is "
+                f"{jax.default_backend()!r}); add --interpret to validate the "
+                f"kernel body off-TPU")
 
     if args.shard and not args.load and args.index != "bruteforce":
         # Fail before the (possibly minutes-long) index build, not after.
@@ -183,6 +186,7 @@ def main() -> None:
         # MicroBatcher groups by (namespace, collection, k, where); per-
         # request knobs would split its coalescing contract.
         raise SystemExit("--rescore-mult does not apply to --micro-batch")
+    print(f"[serve] compilation cache: {compile_cache.enable()}")
 
     if args.load:
         index = MonaVec.load(args.load)

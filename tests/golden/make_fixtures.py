@@ -3,8 +3,10 @@
     PYTHONPATH=src python tests/golden/make_fixtures.py
 
 The fixtures pin the paper's §3.8 byte-identity claim: building the same
-index from the same inputs must produce the same file, byte for byte, on
-any platform (jax threefry + Lloyd-Max codes are platform-deterministic).
+index from the same inputs must produce the same file, byte for byte
+(jax threefry + Lloyd-Max codes are platform-deterministic; f32 norms and
+IVF centroids can differ in the last ulp across XLA versions and devices,
+DESIGN.md §3).
 `tests/test_mvec_golden.py` asserts (a) the checked-in bytes still hash to
 `digests.json`, (b) `load → save` reproduces them exactly, and (c) a fresh
 build reproduces them exactly.  Regenerate ONLY on a deliberate format
