@@ -390,11 +390,14 @@ def _build_plan(backend: Any, extras: Sequence[Any], *, key: PlanKey,
     def marked(fn, stage):
         """jit(fn) with the trace counter attached (runs once per trace) and
         the analysis stage-capture hook on the call path (module docstring:
-        one None-check per call when no observer is installed)."""
+        one None-check per call when no observer is installed).  The
+        program is named after its stage, so a profiler trace shows it as
+        the module ``jit_monavec_<stage>``."""
         def wrapper(*args):
             stats.traces += 1
             obs.inc("plan_cache.traces")
             return fn(*args)
+        wrapper.__name__ = wrapper.__qualname__ = f"monavec_{stage}"
         jitted = jax.jit(wrapper)
 
         def run(*args):
@@ -404,17 +407,16 @@ def _build_plan(backend: Any, extras: Sequence[Any], *, key: PlanKey,
         return run
 
     def staged(stage, fn):
-        """Host-side per-stage timer (DESIGN.md §9): wraps the CALL to a
-        compiled stage — the timer never enters the traced function, so
-        instrumentation cannot perturb the compiled program.  Records into
-        the ``engine.stage_us{backend,stage}`` histogram and, under an
-        active QueryTrace, as a nested span."""
+        """Host-side per-stage span (DESIGN.md §9): wraps the CALL to a
+        compiled stage — the span never enters the traced function, so
+        instrumentation cannot perturb the compiled program.  Recorded
+        under an active QueryTrace and in a profiler trace; no histogram,
+        since on an accelerator the call only enqueues the stage (its
+        device time is the ``jit_monavec_<stage>`` module's)."""
         span_name = f"stage:{stage}"
-        labels = {"backend": kind, "stage": stage}
 
         def run(*args):
-            with obs.timed_span(span_name, histogram="engine.stage_us",
-                                labels=labels):
+            with obs.timed_span(span_name):
                 return fn(*args)
         return run
 
@@ -631,6 +633,16 @@ def _bind_arrays(backend: Any, extras: Sequence[Any],
 # Execution: the one search entry point every backend routes through.
 # ---------------------------------------------------------------------------
 
+def _phase(backend: str, stage: str, /, **attrs: Any):
+    """One host phase of a search (DESIGN.md §9).  prepare -> plan_lookup
+    -> execute -> sync -> finish partition a search's host time in the
+    ``engine.stage_us{backend,stage}`` histograms, and each is a span (a
+    QueryTrace child, and ``monavec.<stage>`` in a profiler trace)."""
+    return obs.timed_span(stage, histogram="engine.stage_us",
+                          labels={"backend": backend, "stage": stage},
+                          attrs=attrs or None)
+
+
 def search_backend(
     backend: Any,
     state: Any,                  # SegmentedState or None (= static index)
@@ -667,89 +679,95 @@ def search_backend(
     when it carries a boost curve, the per-query selectivity boost on
     filtered searches (DESIGN.md §12).
     """
-    _validate_knobs(backend, kwargs)
-    extras = state.extras if state is not None else []
-    knobs = _normalize_knobs(backend, extras, kwargs, k, tuned=tuned)
-    use_kernel, interpret = ops.resolve_dispatch(use_kernel, interpret)
     kind = type(backend).__name__
+    with _phase(kind, "prepare"):
+        _validate_knobs(backend, kwargs)
+        extras = state.extras if state is not None else []
+        knobs = _normalize_knobs(backend, extras, kwargs, k, tuned=tuned)
+        use_kernel, interpret = ops.resolve_dispatch(use_kernel, interpret)
 
-    q = jnp.atleast_2d(jnp.asarray(queries))
-    b = int(q.shape[0])
-    bucket = shape_bucket(b)
-    obs.inc("engine.searches", **{"backend": kind})
-    obs.inc("engine.query_rows", b, **{"backend": kind})
+        q = jnp.atleast_2d(jnp.asarray(queries))
+        b = int(q.shape[0])
+        bucket = shape_bucket(b)
+        obs.inc("engine.searches", **{"backend": kind})
+        obs.inc("engine.query_rows", b, **{"backend": kind})
 
-    base_n = backend.enc.n
-    n_total = int(base_n + sum(s.enc.n for s in extras))
-    if state is not None:
-        live = seg.live_mask(state, allow, base_n)
-    elif allow is not None:
-        mask = np.asarray(allow.mask, dtype=bool)
-        if mask.shape[0] != base_n:
-            raise ValueError(
-                f"allowlist mask covers {mask.shape[0]} rows but the index "
-                f"has {base_n}; build it from the index ids")
-        live = mask
-    else:
-        live = np.ones(base_n, dtype=bool)
-
-    boost = None if tuned is None else getattr(tuned, "boost", None)
-    filtered = where is not None or where_mask is not None
-    # Denominator of the selectivity ratio: live∩allowed rows BEFORE the
-    # caller's filter — "1% selectivity" means 1% of what an unfiltered
-    # search of this index would rank.
-    pre_filter_n = (int(np.count_nonzero(live))
-                    if boost is not None and filtered and knobs else 0)
-
-    if where_mask is not None:
-        wm = np.asarray(where_mask, dtype=bool)
-        if wm.shape != (n_total,):
-            raise ValueError(
-                f"where_mask covers {wm.shape} rows but the index has "
-                f"{n_total}")
-        live = np.asarray(live, dtype=bool) & wm
-
-    where_sig = None
-    where_args: tuple = ()
-    if where is not None:
-        if meta is None or not meta:
-            raise ValueError(
-                "where= requires an index built with metadata columns")
-        if meta.n_rows != n_total:
-            raise ValueError(
-                f"metadata has {meta.n_rows} rows but the index has {n_total}")
-        pred.validate(where, meta)
-        where_sig = pred.structure(where, meta)
-        where_args = tuple(
-            jnp.asarray(a) for a in pred.flatten_args(where, meta))
-
-    # Selectivity-aware candidate budgets (DESIGN.md §12): on filtered
-    # searches of a boost-tuned index, measure how selective the filter is
-    # (exact popcount, cached per predicate structure+constants) and widen
-    # nprobe / rescore_mult via the tuned curve BEFORE plan keying — the
-    # fix for filtered recall collapsing at 1% selectivity.
-    if boost is not None and filtered and knobs and pre_filter_n > 0:
-        if where is not None:
-            from repro.tune.selectivity import estimate_matches
-            matched = estimate_matches(where, meta, live)
+        base_n = backend.enc.n
+        n_total = int(base_n + sum(s.enc.n for s in extras))
+        if state is not None:
+            live = seg.live_mask(state, allow, base_n)
+        elif allow is not None:
+            mask = np.asarray(allow.mask, dtype=bool)
+            if mask.shape[0] != base_n:
+                raise ValueError(
+                    f"allowlist mask covers {mask.shape[0]} rows but the "
+                    f"index has {base_n}; build it from the index ids")
+            live = mask
         else:
-            matched = int(np.count_nonzero(live))
-        mult = boost.multiplier(matched / pre_filter_n)
-        if mult > 1:
-            knobs = _boost_knobs(backend, extras, knobs, k, mult)
-            obs.inc("engine.boost_applied",
-                    **{"backend": kind, "mult": str(mult)})
+            live = np.ones(base_n, dtype=bool)
 
-    fingerprint = _fingerprint(backend, extras, knobs)
-    if where_sig is not None:
-        fingerprint = fingerprint + (("where", where_sig),)
-    key = PlanKey(
-        fingerprint=fingerprint,
-        bucket=bucket, k=k, dispatch=(use_kernel, interpret),
-        knobs=tuple(sorted(knobs.items())),
-    )
-    with obs.timed_span("plan_lookup", histogram="engine.stage_us",
-                        labels={"backend": kind, "stage": "plan_lookup"}) as sp:
+        boost = None if tuned is None else getattr(tuned, "boost", None)
+        filtered = where is not None or where_mask is not None
+        # Denominator of the selectivity ratio: live∩allowed rows BEFORE
+        # the caller's filter — "1% selectivity" means 1% of what an
+        # unfiltered search of this index would rank.
+        pre_filter_n = (int(np.count_nonzero(live))
+                        if boost is not None and filtered and knobs else 0)
+
+        if where_mask is not None:
+            wm = np.asarray(where_mask, dtype=bool)
+            if wm.shape != (n_total,):
+                raise ValueError(
+                    f"where_mask covers {wm.shape} rows but the index has "
+                    f"{n_total}")
+            live = np.asarray(live, dtype=bool) & wm
+
+        where_sig = None
+        where_args: tuple = ()
+        if where is not None:
+            if meta is None or not meta:
+                raise ValueError(
+                    "where= requires an index built with metadata columns")
+            if meta.n_rows != n_total:
+                raise ValueError(
+                    f"metadata has {meta.n_rows} rows but the index has "
+                    f"{n_total}")
+            pred.validate(where, meta)
+            where_sig = pred.structure(where, meta)
+            where_args = tuple(
+                jnp.asarray(a) for a in pred.flatten_args(where, meta))
+
+        # Selectivity-aware candidate budgets (DESIGN.md §12): on filtered
+        # searches of a boost-tuned index, measure how selective the filter
+        # is (exact popcount, cached per predicate structure+constants) and
+        # widen nprobe / rescore_mult via the tuned curve BEFORE plan keying
+        # — the fix for filtered recall collapsing at 1% selectivity.
+        if boost is not None and filtered and knobs and pre_filter_n > 0:
+            if where is not None:
+                from repro.tune.selectivity import estimate_matches
+                matched = estimate_matches(where, meta, live)
+            else:
+                matched = int(np.count_nonzero(live))
+            mult = boost.multiplier(matched / pre_filter_n)
+            if mult > 1:
+                knobs = _boost_knobs(backend, extras, knobs, k, mult)
+                obs.inc("engine.boost_applied",
+                        **{"backend": kind, "mult": str(mult)})
+
+        fingerprint = _fingerprint(backend, extras, knobs)
+        if where_sig is not None:
+            fingerprint = fingerprint + (("where", where_sig),)
+        key = PlanKey(
+            fingerprint=fingerprint,
+            bucket=bucket, k=k, dispatch=(use_kernel, interpret),
+            knobs=tuple(sorted(knobs.items())),
+        )
+        if bucket != b:
+            q = jnp.pad(q, ((0, bucket - b), (0, 0)))
+        q_valid = jnp.asarray(np.arange(bucket) < b)
+        perm = None if backend.enc.perm is None else jnp.asarray(backend.enc.perm)
+
+    with _phase(kind, "plan_lookup") as sp:
         misses_before = _CACHE.stats.misses
         plan = _CACHE.get_or_build(
             key, lambda: _build_plan(backend, extras, key=key, knobs=knobs,
@@ -758,25 +776,20 @@ def search_backend(
             sp.attrs.update(plan=plan_key_digest(key), bucket=bucket, k=k,
                             hit=_CACHE.stats.misses == misses_before)
 
-    if bucket != b:
-        q = jnp.pad(q, ((0, bucket - b), (0, 0)))
-    q_valid = jnp.asarray(np.arange(bucket) < b)
-    perm = None if backend.enc.perm is None else jnp.asarray(backend.enc.perm)
-    with obs.timed_span("execute", histogram="engine.stage_us",
-                        labels={"backend": kind, "stage": "execute"},
-                        attrs={"backend": kind, "rows": b, "bucket": bucket}):
+    with _phase(kind, "execute", backend=kind, rows=b, bucket=bucket):
         vals, pos = plan.fn(q, q_valid, jnp.asarray(live), perm, where_args,
                             *_bind_arrays(backend, extras,
                                           with_codes="rescore_mult" in knobs))
     # The device->host transfer is where outstanding async device work
     # completes: this span/histogram carries the actual device latency.
-    with obs.timed_span("sync", histogram="engine.stage_us",
-                        labels={"backend": kind, "stage": "sync"}):
+    with _phase(kind, "sync"):
         vals = np.asarray(vals)[:b]
         pos = np.asarray(pos)[:b]
-    ids = (backend.ids if not extras else
-           np.concatenate([backend.ids] + [s.ids for s in extras]))
-    return vals, seg.rows_to_ids(pos, ids)
+    with _phase(kind, "finish"):
+        ids = (backend.ids if not extras else
+               np.concatenate([backend.ids] + [s.ids for s in extras]))
+        ids = seg.rows_to_ids(pos, ids)
+    return vals, ids
 
 
 def search_sharded(index: Any, queries: jnp.ndarray, k: int, *,
@@ -798,48 +811,57 @@ def search_sharded(index: Any, queries: jnp.ndarray, k: int, *,
     top-k), normalized exactly like the single-device knob: when m = r*k
     covers the whole corpus the knob drops away and the plan is the plain
     sharded scan (the m=n bit-identity pin)."""
-    q = jnp.atleast_2d(jnp.asarray(queries))
-    b = int(q.shape[0])
-    bucket = shape_bucket(b)
-    enc = index.enc
-    k_eff = min(k, index.n)
-    masked = where_mask is not None
-    if masked:
-        where_mask = np.asarray(where_mask, dtype=bool)
-        if where_mask.shape != (index.n,):
+    with _phase("ShardedMonaVec", "prepare"):
+        q = jnp.atleast_2d(jnp.asarray(queries))
+        b = int(q.shape[0])
+        bucket = shape_bucket(b)
+        enc = index.enc
+        k_eff = min(k, index.n)
+        masked = where_mask is not None
+        if masked:
+            where_mask = np.asarray(where_mask, dtype=bool)
+            if where_mask.shape != (index.n,):
+                raise ValueError(
+                    f"where_mask covers {where_mask.shape} rows but the index "
+                    f"has {index.n}")
+        if rescore_mult is None and tuned is not None:
+            rescore_mult = dict(getattr(tuned, "knobs", {})).get("rescore_mult")
+        rm = 0 if rescore_mult is None else int(rescore_mult)
+        if rm < 0:
+            raise ValueError(f"rescore_mult must be >= 0, got {rm}")
+        boost = None if tuned is None else getattr(tuned, "boost", None)
+        if boost is not None and masked and rm > 0 and index.n > 0:
+            # Sharded corpora are static (no tombstones): selectivity is the
+            # mask's exact popcount over the whole corpus.
+            mult = boost.multiplier(
+                int(np.count_nonzero(where_mask)) / index.n)
+            if mult > 1:
+                rm *= int(mult)
+                obs.inc("engine.boost_applied",
+                        **{"backend": "ShardedMonaVec", "mult": str(mult)})
+        if rm > 0 and enc.ccodes is None:
             raise ValueError(
-                f"where_mask covers {where_mask.shape} rows but the index "
-                f"has {index.n}")
-    if rescore_mult is None and tuned is not None:
-        rescore_mult = dict(getattr(tuned, "knobs", {})).get("rescore_mult")
-    rm = 0 if rescore_mult is None else int(rescore_mult)
-    if rm < 0:
-        raise ValueError(f"rescore_mult must be >= 0, got {rm}")
-    boost = None if tuned is None else getattr(tuned, "boost", None)
-    if boost is not None and masked and rm > 0 and index.n > 0:
-        # Sharded corpora are static (no tombstones): selectivity is the
-        # mask's exact popcount over the whole corpus.
-        mult = boost.multiplier(int(np.count_nonzero(where_mask)) / index.n)
-        if mult > 1:
-            rm *= int(mult)
-            obs.inc("engine.boost_applied",
-                    **{"backend": "ShardedMonaVec", "mult": str(mult)})
-    if rm > 0 and enc.ccodes is None:
-        raise ValueError(
-            "rescore_mult requires an index built with a binarized coarse "
-            "code (MonaVec.build(..., coarse='sign'|'crumb'))")
-    if rm * k_eff >= index.n:
-        rm = 0              # full rescore everywhere == the full scan
-    cascade = rm > 0
-    # Content-keyed like search_backend — the plan must not retain the index:
-    # the closure holds only scalars + the (small, long-lived) mesh, arrays
-    # ride in as arguments, and same-config corpora on one mesh share plans.
-    key = PlanKey(
-        fingerprint=("ShardedMonaVec", id(index.mesh), index.n,
-                     _enc_sig(enc), enc.metric, masked),
-        bucket=bucket, k=k_eff, dispatch=(None, None),
-        knobs=(("rescore_mult", rm),) if cascade else (),
-    )
+                "rescore_mult requires an index built with a binarized coarse "
+                "code (MonaVec.build(..., coarse='sign'|'crumb'))")
+        if rm * k_eff >= index.n:
+            rm = 0              # full rescore everywhere == the full scan
+        cascade = rm > 0
+        # Content-keyed like search_backend — the plan must not retain the
+        # index: the closure holds only scalars + the (small, long-lived)
+        # mesh, arrays ride in as arguments, and same-config corpora on one
+        # mesh share plans.
+        key = PlanKey(
+            fingerprint=("ShardedMonaVec", id(index.mesh), index.n,
+                         _enc_sig(enc), enc.metric, masked),
+            bucket=bucket, k=k_eff, dispatch=(None, None),
+            knobs=(("rescore_mult", rm),) if cascade else (),
+        )
+        n_shards = int(getattr(index.mesh, "size", 1))
+        obs.inc("engine.searches", **{"backend": "ShardedMonaVec"})
+        obs.inc("engine.query_rows", b, **{"backend": "ShardedMonaVec"})
+        if bucket != b:
+            q = jnp.pad(q, ((0, bucket - b), (0, 0)))
+        perm = None if enc.perm is None else jnp.asarray(enc.perm)
 
     def build() -> SearchPlan:
         from repro.dist.retrieval import (make_cascade_topk_shardmap,
@@ -880,41 +902,30 @@ def search_sharded(index: Any, queries: jnp.ndarray, k: int, *,
 
         return SearchPlan(key=key, fn=raw)
 
-    n_shards = int(getattr(index.mesh, "size", 1))
-    obs.inc("engine.searches", **{"backend": "ShardedMonaVec"})
-    obs.inc("engine.query_rows", b, **{"backend": "ShardedMonaVec"})
-    with obs.timed_span("plan_lookup", histogram="engine.stage_us",
-                        labels={"backend": "ShardedMonaVec",
-                                "stage": "plan_lookup"}) as sp:
+    with _phase("ShardedMonaVec", "plan_lookup") as sp:
         plan = _CACHE.get_or_build(key, build)
         if sp is not None:
             sp.attrs.update(plan=plan_key_digest(key), shards=n_shards)
-    if bucket != b:
-        q = jnp.pad(q, ((0, bucket - b), (0, 0)))
-    perm = None if enc.perm is None else jnp.asarray(enc.perm)
-    with obs.timed_span("shard_scan", histogram="engine.stage_us",
-                        labels={"backend": "ShardedMonaVec",
-                                "stage": "shard_scan"},
-                        attrs={"shards": n_shards, "rows": b}):
+    with _phase("ShardedMonaVec", "execute", shards=n_shards, rows=b):
         vals, gidx = plan.fn(q, enc.packed, enc.qnorms,
                              enc.ccodes if cascade else None, perm,
                              jnp.asarray(where_mask) if masked else None)
-    with obs.timed_span("sync", histogram="engine.stage_us",
-                        labels={"backend": "ShardedMonaVec", "stage": "sync"}):
+    with _phase("ShardedMonaVec", "sync"):
         vals = np.asarray(vals)[:b]
         gidx = np.asarray(gidx)
-    ids = index.ids[gidx[:b]]
-    if masked or cascade:
-        # Filtered shards (and cascade shards with dead survivor slots)
-        # surface inadmissible slots as -inf; convert to the engine-wide
-        # sentinel contract (NEG score, SENTINEL_ID id).
-        bad = ~np.isfinite(vals)
-        vals = np.where(bad, NEG, vals).astype(vals.dtype)
-        ids = np.where(bad, seg.SENTINEL_ID, ids)
-    if k_eff < k:   # k > n: sentinel-pad to the full [b, k] contract
-        vals = np.pad(vals, ((0, 0), (0, k - k_eff)), constant_values=NEG)
-        ids = np.pad(ids, ((0, 0), (0, k - k_eff)),
-                     constant_values=seg.SENTINEL_ID)
+    with _phase("ShardedMonaVec", "finish"):
+        ids = index.ids[gidx[:b]]
+        if masked or cascade:
+            # Filtered shards (and cascade shards with dead survivor slots)
+            # surface inadmissible slots as -inf; convert to the engine-wide
+            # sentinel contract (NEG score, SENTINEL_ID id).
+            bad = ~np.isfinite(vals)
+            vals = np.where(bad, NEG, vals).astype(vals.dtype)
+            ids = np.where(bad, seg.SENTINEL_ID, ids)
+        if k_eff < k:   # k > n: sentinel-pad to the full [b, k] contract
+            vals = np.pad(vals, ((0, 0), (0, k - k_eff)), constant_values=NEG)
+            ids = np.pad(ids, ((0, 0), (0, k - k_eff)),
+                         constant_values=seg.SENTINEL_ID)
     return vals, ids
 
 

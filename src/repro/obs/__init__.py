@@ -1,7 +1,9 @@
 # repro.obs — engine-wide observability (DESIGN.md §9): the process-wide
 # MetricsRegistry (counters / gauges / fixed-bucket latency histograms with
 # deterministic edges), the QueryTrace span API with host-side timers that
-# never enter a traced function, and the DeltaStats snapshot/since mixin.
+# never enter a traced function (and land in a JAX profiler trace as
+# ``monavec.<span>`` while one is captured), and the DeltaStats
+# snapshot/since mixin.
 #
 # Instrumentation is additive by contract: a metrics-enabled or traced
 # search returns bytes identical to a disabled one (tests/test_obs.py).
@@ -11,11 +13,11 @@ from .registry import (DEFAULT_COUNT_EDGES, DEFAULT_LATENCY_EDGES_US,
                        counter_deltas, counter_total, enable, enabled, inc,
                        observe, registry, render_key, render_text, set_gauge)
 from .stats import DeltaStats
-from .trace import (QueryTrace, Span, Tracer, current_trace, span, timed_span,
-                    trace)
+from .trace import (PROFILER_PREFIX, QueryTrace, Span, Tracer, current_trace,
+                    span, timed_span, trace)
 
 __all__ = [
-    "DEFAULT_COUNT_EDGES", "DEFAULT_LATENCY_EDGES_US",
+    "DEFAULT_COUNT_EDGES", "DEFAULT_LATENCY_EDGES_US", "PROFILER_PREFIX",
     "Counter", "DeltaStats", "Gauge", "Histogram", "MetricsRegistry",
     "QueryTrace", "Span", "Tracer",
     "counter_deltas", "counter_total", "current_trace", "enable", "enabled",

@@ -14,10 +14,18 @@ transfer of the final top-k) is where outstanding device work completes.
 Per-stage spans are therefore a *structure + dispatch-cost* record on
 accelerators and close to wall time on CPU.  (DESIGN.md §9.)
 
+While the JAX profiler is capturing (``jax.profiler.trace``), every span
+is also written into the profiler's trace as a ``TraceAnnotation`` named
+``monavec.<span name>``, on the same clock as the device's ops: that is
+where a stage's host span and its device time (the ``jit_monavec_<stage>``
+module) are read side by side.  With the profiler off this costs one
+``is_enabled()`` check per span.
+
 The active trace is thread-local: ``with trace("query"):`` activates one,
 any ``span()``/``timed_span()`` underneath nests into it, and a thread with
-no active trace pays a single attribute check.  ``Tracer`` adds 1-in-N
-deterministic sampling for serving loops (`serve.py --trace-sample N`).
+no active trace pays a single attribute check (and the profiler check).
+``Tracer`` adds 1-in-N deterministic sampling for serving loops
+(`serve.py --trace-sample N`).
 """
 
 from __future__ import annotations
@@ -27,11 +35,17 @@ import time
 from contextlib import contextmanager
 from typing import Dict, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from .registry import DEFAULT_LATENCY_EDGES_US
 from .registry import enabled as _metrics_enabled
 from .registry import registry as _registry
 
 _LOCAL = threading.local()
+
+#: Prefix of the spans written into a profiler trace.
+PROFILER_PREFIX = "monavec."
+_profiling = TraceAnnotation.is_enabled
 
 
 class Span:
@@ -146,13 +160,14 @@ _NULL_CM = _NullCm()
 
 class _TimedSpan:
     """Times one host-side block: appends a child span to the active trace
-    (if any) and observes the duration into a registry histogram (if metrics
-    are enabled and a histogram name was given)."""
+    (if any), observes the duration into a registry histogram (if metrics
+    are enabled and a histogram name was given), and opens a profiler
+    annotation (if the profiler is capturing)."""
 
     __slots__ = ("_name", "_hist", "_edges", "_labels", "_attrs",
-                 "_tr", "_sp", "_t0")
+                 "_tr", "_sp", "_t0", "_ann")
 
-    def __init__(self, name, hist, edges, labels, attrs) -> None:
+    def __init__(self, name, hist, edges, labels, attrs, profiled) -> None:
         self._name = name
         self._hist = hist
         self._edges = edges
@@ -161,8 +176,11 @@ class _TimedSpan:
         self._tr = None
         self._sp = None
         self._t0 = 0.0
+        self._ann = TraceAnnotation(PROFILER_PREFIX + name) if profiled else None
 
     def __enter__(self) -> Optional[Span]:
+        if self._ann is not None:
+            self._ann.__enter__()
         self._tr = current_trace()
         if self._tr is not None:
             self._sp = self._tr.push(self._name, **(self._attrs or {}))
@@ -179,6 +197,8 @@ class _TimedSpan:
             _registry().histogram(
                 self._hist, self._edges,
                 **(self._labels or {})).observe(dt_us)
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
         return False
 
 
@@ -186,12 +206,15 @@ def timed_span(name: str, *, histogram: Optional[str] = None,
                edges: Tuple[float, ...] = DEFAULT_LATENCY_EDGES_US,
                labels: Optional[dict] = None,
                attrs: Optional[dict] = None):
-    """Context manager: time a host-side block into ``histogram`` (us) and,
-    when a trace is active, record it as a nested span.  Free (a shared
-    null object) when there is nothing to record."""
-    if current_trace() is None and (histogram is None or not _metrics_enabled()):
+    """Context manager: time a host-side block into ``histogram`` (us),
+    record it as a nested span when a trace is active, and write it into
+    the profiler's trace as ``monavec.<name>`` while the profiler captures.
+    Free (a shared null object) when there is nothing to record."""
+    profiled = _profiling()
+    if (not profiled and current_trace() is None
+            and (histogram is None or not _metrics_enabled())):
         return _NULL_CM
-    return _TimedSpan(name, histogram, edges, labels, attrs)
+    return _TimedSpan(name, histogram, edges, labels, attrs, profiled)
 
 
 def span(name: str, **attrs: object):

@@ -19,6 +19,7 @@ import dataclasses
 import json
 import logging
 
+import jax
 import numpy as np
 import pytest
 
@@ -305,7 +306,7 @@ class TestTrace:
 # ---------------------------------------------------------------------------
 
 class TestBitIdentity:
-    def test_enabled_disabled_and_traced_searches_identical(self):
+    def test_enabled_disabled_and_traced_searches_identical(self, tmp_path):
         idx = _index(n=96, dim=24, seed=3)
         rng = np.random.RandomState(7)
         q = rng.randn(5, 24).astype(np.float32)
@@ -318,11 +319,13 @@ class TestBitIdentity:
             obs.enable(prev)
         with obs.trace("bit-identity"):
             vals_tr, ids_tr = idx.search(q, k=10)
+        with jax.profiler.trace(str(tmp_path)):
+            vals_pr, ids_pr = idx.search(q, k=10)
 
-        assert np.asarray(vals_on).tobytes() == np.asarray(vals_off).tobytes()
-        assert np.asarray(ids_on).tobytes() == np.asarray(ids_off).tobytes()
-        assert np.asarray(vals_on).tobytes() == np.asarray(vals_tr).tobytes()
-        assert np.asarray(ids_on).tobytes() == np.asarray(ids_tr).tobytes()
+        for vals, ids in ((vals_off, ids_off), (vals_tr, ids_tr),
+                          (vals_pr, ids_pr)):
+            assert np.asarray(vals_on).tobytes() == np.asarray(vals).tobytes()
+            assert np.asarray(ids_on).tobytes() == np.asarray(ids).tobytes()
 
     def test_trace_captures_engine_stages(self):
         idx = _index(n=64, dim=16, seed=5)
@@ -330,9 +333,75 @@ class TestBitIdentity:
         idx.search(q, k=5)                      # warm the plan outside
         with obs.trace("q") as tr:
             idx.search(q, k=5)
-        names = [c["name"] for c in tr.to_dict()["children"]]
-        assert names[0] == "plan_lookup"
-        assert "execute" in names and "sync" in names
+        children = tr.to_dict()["children"]
+        assert [c["name"] for c in children] == ENGINE_PHASES
+        execute = children[ENGINE_PHASES.index("execute")]
+        assert [c["name"] for c in execute["children"]] == [
+            "stage:rotate", "stage:scan", "stage:finalize"]
+
+
+#: The engine's host phases, in the order one search runs them.
+ENGINE_PHASES = ["prepare", "plan_lookup", "execute", "sync", "finish"]
+
+
+def _stage_histograms():
+    return {k: h["count"] for k, h in obs.registry().snapshot()["histograms"].items()
+            if k.startswith("engine.stage_us")}
+
+
+class TestEnginePhases:
+    def test_phase_histograms_partition_the_search(self):
+        """Every engine phase feeds ``engine.stage_us``; the plan stages
+        inside ``execute`` feed no histogram."""
+        idx = _index(n=64, dim=16, seed=5)
+        q = np.random.RandomState(1).randn(3, 16).astype(np.float32)
+        idx.search(q, k=5)
+        before = _stage_histograms()
+        idx.search(q, k=5)
+        grew = {k for k, n in _stage_histograms().items() if n > before.get(k, 0)}
+        assert grew == {
+            f'engine.stage_us{{backend="BruteForceIndex",stage="{p}"}}'
+            for p in ENGINE_PHASES}
+
+    def test_profiler_trace_carries_phases_and_named_stages(self, tmp_path):
+        """One search under ``jax.profiler.trace`` writes each phase as a
+        ``monavec.*`` span, in order and inside the caller's annotation, with
+        the stage spans and their ``monavec_<stage>`` programs inside
+        ``execute`` — also with metrics off and no QueryTrace active."""
+        from jax.profiler import ProfileData
+
+        idx = _index(n=64, dim=16, seed=5)
+        q = np.random.RandomState(1).randn(3, 16).astype(np.float32)
+        idx.search(q, k=5)
+        prev = obs.enable(False)
+        try:
+            with jax.profiler.trace(str(tmp_path)):
+                with jax.profiler.TraceAnnotation("caller"):
+                    idx.search(q, k=5)
+        finally:
+            obs.enable(prev)
+        [path] = tmp_path.rglob("*.xplane.pb")
+        events = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                  for plane in ProfileData.from_file(str(path)).planes
+                  for line in plane.lines for e in line.events]
+
+        def spans(name):
+            return [(s, t) for n, s, t in events if n == name]
+
+        [(c0, c1)] = spans("caller")
+        phases = [spans(obs.PROFILER_PREFIX + p) for p in ENGINE_PHASES]
+        assert all(len(p) == 1 for p in phases)
+        phases = [p[0] for p in phases]
+        assert c0 <= phases[0][0] and phases[-1][1] <= c1
+        for (_, end), (start, _) in zip(phases, phases[1:]):
+            assert end <= start
+        e0, e1 = phases[ENGINE_PHASES.index("execute")]
+        for stage in ("rotate", "scan", "finalize"):
+            [(s0, s1)] = spans(f"{obs.PROFILER_PREFIX}stage:{stage}")
+            assert e0 <= s0 and s1 <= e1
+            program = spans(f"PjitFunction(monavec_{stage})")
+            assert program and all(s0 <= a and b <= s1 for a, b in program)
+        assert not spans("PjitFunction(wrapper)")
 
 
 # ---------------------------------------------------------------------------
