@@ -207,31 +207,29 @@ def crumb_coarse_raw(
     """Crumb affinities [b, n] (int32) from plane-packed codes.
 
     Both byte arrays carry the hi bit plane then the lo bit plane, each
-    d'/8 bytes (binary.derive_codes / binary.query_crumb_planes layout);
-    zero pad rows AND to 0 and popcount to 0, so padding is free.
+    d'/8 bytes (binary.derive_codes / binary.query_crumb_planes layout).
+    The kernel takes the query as int8 levels; pad queries are level 0 and
+    pad plane bytes meet level-0 query dims, so padding contributes 0.
     """
     use_kernel, interpret = resolve_dispatch(use_kernel, interpret)
-    dkp = ccodes.shape[-1] // 2
-    dim = dkp * 8
-    chi, clo = ccodes[:, :dkp], ccodes[:, dkp:]
-    qhi, qlo = qplanes[:, :dkp], qplanes[:, dkp:]
-    if not use_kernel:
-        return binary_dot.crumb_affinity_jnp(chi, clo, qhi, qlo, dim=dim)
-
-    n = ccodes.shape[0]
+    n, w = ccodes.shape
     b = qplanes.shape[0]
-    bq = min(8, _round_up(b, 8))
-    bn = min(256, _round_up(n, 128))
-    bk = min(128, dkp)       # dkp is a power of two (d' = pow2 >= 8), so bk | dkp
-    b_pad, n_pad = _round_up(b, bq), _round_up(n, bn)
-    pad_c = ((0, n_pad - n), (0, 0))
-    pad_q = ((0, b_pad - b), (0, 0))
+    dkp = w // 2
+    if not use_kernel:
+        return binary_dot.crumb_affinity_jnp(
+            ccodes[:, :dkp], ccodes[:, dkp:], qplanes[:, :dkp], qplanes[:, dkp:],
+            dim=dkp * 8)
+
+    kp = _round_up(dkp, binary_dot.LANE)
+    if kp != dkp:        # d' < 1024: widen each plane to whole lanes
+        ccodes = jnp.pad(ccodes.reshape(n, 2, dkp),
+                         ((0, 0), (0, 0), (0, kp - dkp))).reshape(n, 2 * kp)
+    bq, bn = binary_dot.crumb_blocks(b, n, kp)
+    qlev = binary_dot.crumb_query_levels(qplanes)
+    qlev = jnp.pad(qlev, ((0, 0), (0, _round_up(b, bq) - b), (0, kp - dkp)))
     out = binary_dot.crumb_affinity_raw(
-        jnp.pad(chi, pad_c), jnp.pad(clo, pad_c),
-        jnp.pad(qhi, pad_q), jnp.pad(qlo, pad_q),
-        dim=dim, block_q=bq, block_n=bn, block_k=bk, interpret=interpret,
-    )
-    return out[:b, :n]
+        ccodes, qlev, block_q=bq, block_n=bn, interpret=interpret)
+    return out[:b]
 
 
 # ---------------------------------------------------------------------------

@@ -30,7 +30,7 @@ from repro.core import binary
 from repro.core import quantize as qz
 from repro.core.allowlist import NEG
 from repro.data import synthetic as syn
-from repro.kernels import ops
+from repro.kernels import binary_dot, ops
 
 K = 10
 
@@ -94,6 +94,51 @@ class TestCoarseMirrorBitIdentity:
                                        use_kernel=True, interpret=True)
         assert ref.shape == (3, 257)
         np.testing.assert_array_equal(np.asarray(ref), np.asarray(ker))
+
+    # (b, n, d'): every batch the blocks treat differently (one 8-row block,
+    # a full 256-row block, two blocks), rows no row tile divides (257,
+    # 1,000) and rows several do (4,096), and a plane narrower than a lane
+    # (d'=16: 2 bytes), exactly one lane (d'=1024: 128 bytes) and wider.
+    CRUMB_SHAPES = [(1, 257, 16), (3, 1000, 2048), (8, 4096, 16),
+                    (256, 1000, 16), (300, 257, 1024), (300, 4096, 2048),
+                    (1, 4096, 1024), (8, 257, 2048), (256, 4096, 1024)]
+
+    @staticmethod
+    def _crumb_pair(ccodes, qplanes):
+        """(kernel in interpret mode, popcount mirror) on raw plane bytes."""
+        ker = ops.crumb_coarse_raw(ccodes, qplanes, use_kernel=True,
+                                   interpret=True)
+        dkp = ccodes.shape[1] // 2
+        mirror = binary_dot.crumb_affinity_jnp(
+            ccodes[:, :dkp], ccodes[:, dkp:], qplanes[:, :dkp],
+            qplanes[:, dkp:], dim=8 * dkp)
+        assert ker.dtype == mirror.dtype == jnp.int32
+        assert ker.shape == mirror.shape == (qplanes.shape[0], ccodes.shape[0])
+        return np.asarray(ker), np.asarray(mirror)
+
+    @pytest.mark.parametrize("b,n,dim", CRUMB_SHAPES,
+                             ids=[f"b{b}-n{n}-d{d}" for b, n, d in CRUMB_SHAPES])
+    def test_crumb_level_dot_equals_popcount_mirror(self, b, n, dim):
+        """The kernel's int8 level dot and the mirror's AND + popcount
+        identity are two formulas for one integer: == at every tiling."""
+        rng = np.random.default_rng([b, n, dim])
+        ccodes = jnp.asarray(rng.integers(0, 256, (n, dim // 4), np.uint8))
+        qplanes = jnp.asarray(rng.integers(0, 256, (b, dim // 4), np.uint8))
+        ker, mirror = self._crumb_pair(ccodes, qplanes)
+        assert (ker == mirror).all()
+
+    @pytest.mark.parametrize("q_byte,c_byte", [(0, 0), (0, 255), (255, 0),
+                                               (255, 255)])
+    def test_crumb_extremes(self, q_byte, c_byte):
+        """All-zero planes are level -3 in every dim, all-ones +3: equal
+        planes read exactly +9 d', opposite ones -9 d' (the level map, its
+        sign, and no int8 overflow)."""
+        b, n, dim = 3, 257, 2048
+        ccodes = jnp.full((n, dim // 4), c_byte, jnp.uint8)
+        qplanes = jnp.full((b, dim // 4), q_byte, jnp.uint8)
+        ker, mirror = self._crumb_pair(ccodes, qplanes)
+        want = 9 * dim if q_byte == c_byte else -9 * dim
+        assert (ker == want).all() and (mirror == want).all()
 
 
 # ---------------------------------------------------------------------------

@@ -6,7 +6,8 @@ Mosaic (the TPU kernel compiler) does not.  These tests lower every kernel
 entry point through the ``ops.py`` wrappers with ``use_kernel=True,
 interpret=False`` -- so the real padding and block choice are exercised --
 and compile it for one chip of a described (not attached) ``v5e:2x2``
-topology, at the paper's AG News shape (45,056 rows, d'=1024).
+topology, at the paper's AG News shape (45,056 rows, d'=1024), and the
+crumb coarse scan also at GIST-1M's 1,000,000 rows.
 
 The topology is described inside a module-scoped fixture, never at import:
 only one process may load the TPU library at a time, and every test worker
@@ -16,6 +17,7 @@ imports this file.  All compiles stay in this one file for the same reason.
 from __future__ import annotations
 
 import functools
+import re
 
 import jax
 import jax.numpy as jnp
@@ -25,6 +27,7 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import ops
 
 N_ROWS = 45_056      # AG News corpus rows (configs/retrieval.py agnews_45k)
+GIST_ROWS = 1_000_000  # GIST-1M rows (bench/configs/gist1m.json), d' = 1024
 DIM = 1024           # rotated dim d'
 BATCHES = (8, 256)   # single-query-ish and the benchmark batch
 
@@ -63,6 +66,18 @@ def _compile_text(fn, sharding, *shapes) -> str:
     return jax.jit(fn).lower(*args).compile().as_text()
 
 
+def _custom_call_names(text: str) -> list:
+    """Names of the compiled module's custom calls (``%name = ... custom-call(``)."""
+    return re.findall(r"%([\w.-]+) = [^\n]*? custom-call\(", text)
+
+
+def _assert_crumb_kernel_named(text: str) -> None:
+    """bench/metrics/crumb_dot_roofline.py finds the kernel's device op by
+    this name: the custom call takes it from its jitted function."""
+    names = _custom_call_names(text)
+    assert any(nm.startswith("crumb_affinity_raw.") for nm in names), names
+
+
 @pytest.mark.parametrize("b", BATCHES)
 @pytest.mark.parametrize("bits", [4, 2])
 def test_full_scan_compiles(one_chip, bits, b):
@@ -88,6 +103,20 @@ def test_coarse_scan_compiles(one_chip, kind, b):
                          ((N_ROWS, width), jnp.uint8),
                          ((b, width), jnp.uint8))
     assert "tpu_custom_call" in text
+    if kind == "crumb":
+        _assert_crumb_kernel_named(text)
+
+
+@pytest.mark.parametrize("b", BATCHES)
+def test_crumb_coarse_compiles_at_gist_shape(one_chip, b):
+    """The crumb level-dot kernel over GIST-1M's crumb codes: 1M rows that
+    no row tile divides, so the last tile is ragged."""
+    fn = functools.partial(ops.crumb_coarse_raw, use_kernel=True,
+                           interpret=False)
+    text = _compile_text(fn, one_chip,
+                         ((GIST_ROWS, DIM // 4), jnp.uint8),
+                         ((b, DIM // 4), jnp.uint8))
+    _assert_crumb_kernel_named(text)
 
 
 @pytest.mark.parametrize("mc", GATHER_WIDTHS)
