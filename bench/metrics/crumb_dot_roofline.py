@@ -2,8 +2,10 @@
 
 The 2-bit levels fit int8, so the least time is 2 b n d' operations at the
 int8 peak, or reading n d'/4 crumb bytes and b d'/4 query bytes at the HBM
-peak, whichever is longer.  Share = events x least time / summed device time
-of the kernel's events.
+peak, whichever is longer, where n is the rows one event scans: a corpus
+sharded over the cell's chips runs one event per chip over its
+``rows_per_chip`` rows.  Share = events x least time / summed device time of
+the kernel's events.
 """
 
 from bench import trace
@@ -22,5 +24,5 @@ def read(ctx):
     if not ev:
         return None
     c = ctx["cell"]
-    least = least_seconds(c["bucket"], c["n"], c["d_pad"], ctx["peaks"])
+    least = least_seconds(c["bucket"], c["rows_per_chip"], c["d_pad"], ctx["peaks"])
     return 100.0 * len(ev) * least / (sum(d for _, _, d in ev) / 1e9)

@@ -3,8 +3,10 @@
 The least time counts the work of the operation, not of its implementation:
 2 b n d' multiply-adds at the bf16 peak (no float32 peak is published, so
 the bf16 one is the ceiling), or reading n d'/2 code bytes, 4 n bytes of
-norms and 4 b d' bytes of queries at the HBM peak, whichever is longer.
-Share = events x least time / summed device time of the kernel's events.
+norms and 4 b d' bytes of queries at the HBM peak, whichever is longer,
+where n is the rows one event scans: a corpus sharded over the cell's chips
+runs one event per chip over its ``rows_per_chip`` rows.  Share = events x
+least time / summed device time of the kernel's events.
 """
 
 from bench import trace
@@ -23,5 +25,5 @@ def read(ctx):
     if not ev:
         return None
     c = ctx["cell"]
-    least = least_seconds(c["bucket"], c["n"], c["d_pad"], ctx["peaks"])
+    least = least_seconds(c["bucket"], c["rows_per_chip"], c["d_pad"], ctx["peaks"])
     return 100.0 * len(ev) * least / (sum(d for _, _, d in ev) / 1e9)

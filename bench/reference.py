@@ -31,15 +31,18 @@ l2, |q| for cosine), and ``answer_gap`` is the largest over the checked
 queries.  A returned row that is out of range, repeated, out of score order
 or (cascade) cannot be a survivor at all counts as STRUCTURAL.
 
-Work is split in two tiers so that a million rows fit in the run's time.
-The device tier rotates and codes every row in float32 (HIGHEST), scores it
-with a generous error margin, and computes the integer proxy intervals
-exactly; it only nominates candidate rows.  The host tier recomputes the
-candidates and the returned rows exactly, in float64.
+Work is split in two tiers so that tens of millions of rows fit in the
+run's time.  The device tier rotates and codes every row in float32
+(HIGHEST), scores it with a generous error margin, and computes the integer
+proxy intervals exactly; it only nominates candidate rows.  It streams the
+corpus chunk by chunk, on the devices that make its blocks, so no device and
+not the host ever holds the corpus or an [nq, n] array.  The host tier
+recomputes the candidates and the returned rows exactly, in float64.
 """
 
 from __future__ import annotations
 
+import collections
 import functools
 import itertools
 from typing import NamedTuple, Optional
@@ -329,28 +332,114 @@ def _device_chunk(x, q_rot, q_raw, lq, lq_step, sgn, had, mean, inv_std,
     return out, ex_v, ex_i
 
 
-@functools.partial(jax.jit, static_argnames=("m", "k"))
-def _survivor_bounds(p_lo, p_hi, *, m, k):
-    """Rows that surely / possibly rank among the m best proxies (ties to the
-    lower row), given per-row proxy intervals."""
-    def one_side(a, b):
-        # rows j with b_j above the m-th largest a, or tied at it and early
-        # enough in row order that fewer than m rows can precede them.
-        am = jax.lax.top_k(a, m)[0][:, -1:]
-        above = jnp.sum((a > am).astype(jnp.int32), axis=1, keepdims=True)
-        tie = (a == am).astype(jnp.int32)
-        before = jnp.cumsum(tie, axis=1) - tie
-        return (b > am) | ((b == am) & (above + before < m))
-    sure = one_side(p_hi, p_lo)
-    possible = one_side(p_lo, p_hi)
-    return sure, possible
+@functools.partial(jax.jit, static_argnames=("top", "k"))
+def _thresholds(out, valid, a, *, top, k):
+    """Pass 1 over one chunk: the k best lower score bounds (the full scan's
+    threshold) and the ``top`` best of each proxy bound, with their rows,
+    ties to the lower row.  Columns from ``valid`` on are padding."""
+    cols = jnp.arange(out["s32"].shape[1])[None, :]
+    ok = cols < valid
+    rows = (a + cols).astype(jnp.int32)
+    res = {}
+    if "p_hi" not in out:
+        res["lower"] = _top(jnp.where(ok, out["s32"] - out["margin"], -jnp.inf), rows, k)
+    for key in ("p_hi", "p_lo", "p_nom"):
+        if key in out:
+            res[key] = _top(jnp.where(ok, out[key], jnp.iinfo(jnp.int32).min), rows, top)
+    if "s_ctrl" in out:
+        res["s_ctrl"] = _top(jnp.where(ok, out["s_ctrl"], -jnp.inf), rows, k)
+    return res
+
+
+def _top(v, rows, m):
+    v, i = jax.lax.top_k(v, min(m, v.shape[1]))
+    return v, jnp.take_along_axis(jnp.broadcast_to(rows, (v.shape[0], rows.shape[1])), i, 1)
+
+
+@functools.partial(jax.jit, static_argnames=("m",))
+def _merge(v0, r0, v1, r1, *, m):
+    """The m best of two lists, each best first with ties to the lower row,
+    where every row of the first lies below every row of the second."""
+    v, i = jax.lax.top_k(jnp.concatenate([v0, v1], 1), min(m, v0.shape[1] + v1.shape[1]))
+    return v, jnp.take_along_axis(jnp.concatenate([r0, r1], 1), i, 1)
+
+
+def _one_side(b, am, cut, rows):
+    """Rows whose b lies above ``am``, the m-th best of a over all rows, or
+    at it and no later than ``cut``, the row in m-th place when a is ranked
+    with ties to the lower row: no more than m rows can then rank before
+    them, as a count of the tied rows before each row in row order says."""
+    return (b > am) | ((b == am) & (rows <= cut))
 
 
 @functools.partial(jax.jit, static_argnames=("k",))
-def _candidates(s32, margin, sure, *, k):
-    lower = jnp.where(sure, s32 - margin, -jnp.inf)
-    t = jax.lax.top_k(lower, k)[0][:, -1:]
-    return sure & (s32 + margin >= t)
+def _sure_lower(out, valid, a, thr, *, k):
+    """Pass 2 (cascade) over one chunk: the k best lower score bounds among
+    rows that surely survive."""
+    cols = jnp.arange(out["s32"].shape[1])[None, :]
+    rows = (a + cols).astype(jnp.int32)
+    sure = (cols < valid) & _one_side(out["p_lo"], thr["am_hi"], thr["cut_hi"], rows)
+    return _top(jnp.where(sure, out["s32"] - out["margin"], -jnp.inf), rows, k)
+
+
+@jax.jit
+def _gather_ctrl(out, valid, a, surv, best):
+    """Pass 2 (cascade control) over one chunk: the control's scores at its
+    survivor rows that lie in this chunk, kept in ``best``."""
+    size = out["s_ctrl"].shape[1]
+    inside = (surv >= a) & (surv < a + valid)
+    g = jnp.take_along_axis(out["s_ctrl"], jnp.clip(surv - a, 0, size - 1), 1)
+    return jnp.maximum(best, jnp.where(inside, g, -jnp.inf))
+
+
+@jax.jit
+def _candidates(out, valid, a, t, thr, ids):
+    """Last pass over one chunk: the rows whose upper score bound reaches
+    the query's threshold ``t`` among rows that surely survive, as little-
+    endian bits, and whether each answered row surely / possibly survives."""
+    size = out["s32"].shape[1]
+    cols = jnp.arange(size)[None, :]
+    rows = (a + cols).astype(jnp.int32)
+    sure = cols < valid
+    inside = (ids >= a) & (ids < a + valid)
+    local = jnp.clip(ids - a, 0, size - 1)
+    if thr is None:
+        sure_at = possible_at = inside
+    else:
+        sure = sure & _one_side(out["p_lo"], thr["am_hi"], thr["cut_hi"], rows)
+        lo_at = jnp.take_along_axis(out["p_lo"], local, 1)
+        hi_at = jnp.take_along_axis(out["p_hi"], local, 1)
+        sure_at = inside & _one_side(lo_at, thr["am_hi"], thr["cut_hi"], ids)
+        possible_at = inside & _one_side(hi_at, thr["am_lo"], thr["cut_lo"], ids)
+    cand = sure & (out["s32"] + out["margin"] >= t)
+    return jnp.packbits(cand, axis=1, bitorder="little"), sure_at, possible_at
+
+
+class _Best:
+    """The m best values per query with their rows, best first, ties to the
+    lower row: one running list per device (its chunks come in row order),
+    merged on the host."""
+
+    def __init__(self, m: int):
+        self.m, self.by_device = m, {}
+
+    def add(self, device, vr) -> None:
+        cur = self.by_device.get(device)
+        self.by_device[device] = vr if cur is None else _merge(*cur, *vr, m=self.m)
+
+    def result(self):
+        v = np.concatenate([np.asarray(v) for v, _ in self.by_device.values()], 1)
+        r = np.concatenate([np.asarray(r) for _, r in self.by_device.values()], 1)
+        order = np.lexsort((r, -v.astype(np.float64)), axis=1)[:, :self.m]
+        return np.take_along_axis(v, order, 1), np.take_along_axis(r, order, 1)
+
+
+def _bit_rows(packed: np.ndarray, a: int):
+    """(query, row) of every set bit of little-endian packed rows from ``a``."""
+    qi, byte = np.nonzero(packed)
+    bits = np.unpackbits(packed[qi, byte][:, None], axis=1, bitorder="little")
+    qq, bb = np.nonzero(bits)
+    return qi[qq], a + 8 * byte[qq].astype(np.int64) + bb
 
 
 class Verdict(NamedTuple):
@@ -366,82 +455,172 @@ class Verdict(NamedTuple):
     control_score_mean: Optional[float]
 
 
-def _chunks(n: int, rows: int):
-    n_chunks = -(-n // rows)
-    size = -(-n // n_chunks)
-    return [(i, min(i + size, n)) for i in range(0, n, size)], size
+def chunks(source, chunk_rows: int):
+    """([(a, b)], size): row ranges of at most ``chunk_rows`` rows of equal
+    padded ``size``, each inside one block of ``source`` (a ``datagen.Rows``)."""
+    b0 = source.block_rows
+    size = -(-b0 // -(-b0 // chunk_rows))
+    return [(j * b0 + a, min(j * b0 + a + size, (j + 1) * b0, source.n))
+            for j in range(source.n_blocks) for a in range(0, b0, size)
+            if j * b0 + a < source.n], size
 
 
-def check(x_dev, q: np.ndarray, got_scores: np.ndarray, got_ids: np.ndarray,
+def _pass(source, bounds, size, run, done=None):
+    """``run(device, a, b, x)`` on every chunk [a, b) of rows ``x``, padded to
+    ``size`` rows, on its block's device, then ``done(a, result)`` in chunk
+    order.  A few chunks are in flight per device: queued work holds memory."""
+    blk = None
+    pending = collections.deque()
+
+    def finish():
+        a, res = pending.popleft()
+        jax.block_until_ready(res)
+        if done is not None:
+            done(a, res)
+    for a, b in bounds:
+        j = a // source.block_rows
+        if blk is None or blk[0] != j:
+            blk = (j, source.block(j))
+        la = a - j * source.block_rows
+        xc = blk[1][la:la + (b - a)]
+        if b - a < size:
+            xc = jnp.pad(xc, ((0, size - (b - a)), (0, 0)))
+        pending.append((a, run(source.device_of(j), a, b, xc)))
+        if len(pending) > 2 * len(source.devices):
+            finish()
+    while pending:
+        finish()
+
+
+def check(source, q: np.ndarray, got_scores: np.ndarray, got_ids: np.ndarray,
           sem: Semantics, *, k: int, m: Optional[int], control: bool = False,
           chunk_rows: int = 65536) -> Verdict:
     """Judge the answers ``(got_scores, got_ids)`` [nq, k] to queries ``q``.
 
-    ``x_dev`` is the corpus as the program was given it (on the device);
-    ``m`` is the cascade's survivor count (None: the full scan).  With
-    ``control`` the reference also answers the queries itself at
-    ``Precision.HIGH`` and reports how that answer is judged.
+    ``source`` (a ``datagen.Rows``) makes the corpus as the program was
+    given it, chunk by chunk on the device; ``m`` is the cascade's survivor
+    count (None: the full scan).  With ``control`` the reference also
+    answers the queries itself at ``Precision.HIGH`` and reports how that
+    answer is judged.
+
+    The device tier streams the corpus in chunks and keeps per query only
+    order statistics and sparse row lists, never an [nq, n] array: pass 1
+    the k best lower score bounds and exact scores (recall), and the m best
+    of each proxy bound; in the cascade, pass 2 the k best lower bounds
+    among rows that surely survive (and the control's survivor scores); a
+    last pass the candidate rows.  The order statistics are exact, so the
+    verdict is that of one pass over all rows at once.
     """
-    n = int(x_dev.shape[0])
+    n = int(source.n)
     nq = len(q)
+    cascade = m is not None and m < n
     q_prep = prepare64(q, sem)
     q_rot = rotate64(q, sem)
     q_scale = np.linalg.norm(q_prep, axis=1)
     lq, lq_step = query_crumbs(q_rot, q_scale)
-    args = [jnp.asarray(q_rot, jnp.float32), jnp.asarray(q, jnp.float32),
-            jnp.asarray(lq), jnp.asarray(lq_step),
-            jnp.asarray(sem.signs, jnp.float32),
-            jnp.asarray(sem.had, jnp.float32),
-            jnp.float32(sem.mean), jnp.float32(sem.inv_std),
-            jnp.asarray(CENTROIDS4, jnp.float32), jnp.asarray(BOUNDARIES4, jnp.float32)]
+    host_args = [q_rot.astype(np.float32), np.asarray(q, np.float32), lq, lq_step,
+                 sem.signs.astype(np.float32), sem.had.astype(np.float32),
+                 np.float32(sem.mean), np.float32(sem.inv_std),
+                 CENTROIDS4.astype(np.float32), BOUNDARIES4.astype(np.float32)]
+    on_device = {}
 
-    bounds, size = _chunks(n, chunk_rows)
-    parts, ex_v, ex_i = [], [], []
-    for a, b in bounds:
-        xc = x_dev[a:b]
-        if b - a < size:
-            xc = jnp.pad(xc, ((0, size - (b - a)), (0, 0)))
-        out, v, j = _device_chunk(xc, *args, metric=sem.metric, control=control, k=k)
-        parts.append({key: arr[:, :b - a] for key, arr in out.items()})
+    def chunk_out(device, xc):
+        if device not in on_device:
+            on_device[device] = jax.device_put(host_args, device)
+        return _device_chunk(xc, *on_device[device], metric=sem.metric,
+                             control=control, k=k)
+
+    bounds, size = chunks(source, chunk_rows)
+    top = m if cascade else k
+    best = {key: _Best(top) for key in ("p_hi", "p_lo", "p_nom")}
+    best.update(lower=_Best(k), s_ctrl=_Best(k), exact=_Best(k))
+
+    def pass1(device, a, b, xc):
+        out, v, j = chunk_out(device, xc)
         v = jnp.where(j < b - a, v, -jnp.inf)      # padded rows never count
-        ex_v.append(np.asarray(v))
-        ex_i.append(np.asarray(j) + a)
-    dev = {key: jnp.concatenate([p[key] for p in parts], axis=1) for key in parts[0]}
-    del parts
-    ex_v, ex_i = np.concatenate(ex_v, axis=1), np.concatenate(ex_i, axis=1)
-    exact_top = np.take_along_axis(
-        ex_i, np.argsort(-ex_v, axis=1, kind="stable")[:, :k], axis=1)
+        best["exact"].add(device, (v, j + a))
+        keys = ("p_hi", "p_lo", "p_nom") if cascade else ("s_ctrl",)
+        res = _thresholds({key: out[key] for key in ("s32", "margin") + keys if key in out},
+                          b - a, a, top=top, k=k)
+        for key, vr in res.items():
+            best[key].add(device, vr)
+        return res
+    _pass(source, bounds, size, pass1)
+    exact_top = best["exact"].result()[1]
 
-    cascade = m is not None and m < n
-    if cascade:
-        sure, possible = _survivor_bounds(dev["p_lo"], dev["p_hi"], m=m, k=k)
-    else:
-        sure = possible = jnp.ones((nq, n), bool)
-    cand = np.asarray(_candidates(dev["s32"], dev["margin"], sure, k=k))
-    possible_h = np.asarray(possible)
-    sure_h = np.asarray(sure)
-
+    thr = None
     ctrl_scores = ctrl_ids = None
-    if control:
-        if cascade:
-            surv = jax.lax.top_k(dev["p_nom"], m)[1]
-            s = jnp.take_along_axis(dev["s_ctrl"], surv, axis=1)
-            v, j = jax.lax.top_k(s, k)
-            ctrl_ids, ctrl_scores = np.asarray(jnp.take_along_axis(surv, j, 1)), np.asarray(v)
-        else:
-            v, i = jax.lax.top_k(dev["s_ctrl"], k)
-            ctrl_scores, ctrl_ids = np.asarray(v), np.asarray(i)
-    del dev
+    if cascade:
+        thr = {}
+        for side, key in (("hi", "p_hi"), ("lo", "p_lo")):
+            v, r = best[key].result()
+            thr["am_" + side], thr["cut_" + side] = v[:, -1:], r[:, -1:]
+        surv = best["p_nom"].result()[1] if control else None
+        lower, ctrl_best = _Best(k), {}
+
+        def pass2(device, a, b, xc):
+            out = chunk_out(device, xc)[0]
+            res = _sure_lower(out, b - a, a, jax.device_put(thr, device), k=k)
+            lower.add(device, res)
+            if control:
+                ctrl_best[device] = _gather_ctrl(
+                    out, b - a, a, jax.device_put(surv, device),
+                    ctrl_best.get(device, jax.device_put(
+                        np.full(surv.shape, -np.inf, np.float32), device)))
+            return res
+        _pass(source, bounds, size, pass2)
+        t = lower.result()[0][:, -1:]
+        if control:
+            s = np.max(np.stack([np.asarray(v) for v in ctrl_best.values()]), axis=0)
+            v, j = jax.lax.top_k(jnp.asarray(s), k)
+            ctrl_ids = np.take_along_axis(surv, np.asarray(j), 1).astype(np.int64)
+            ctrl_scores = np.asarray(v)
+    else:
+        t = best["lower"].result()[0][:, -1:]
+        if control:
+            ctrl_scores, ctrl_ids = best["s_ctrl"].result()
+            ctrl_ids = ctrl_ids.astype(np.int64)
 
     answers = [(got_scores, got_ids)] + ([(ctrl_scores, ctrl_ids)] if control else [])
+    all_ids = np.concatenate([np.asarray(ids, np.int64) for _, ids in answers], axis=1)
+    ids_dev = np.clip(all_ids, -1, n).astype(np.int32)
+    cand_rows = [[] for _ in range(nq)]
+    n_found = np.zeros(nq, np.int64)
+    sure_at = np.zeros(all_ids.shape, bool)
+    possible_at = np.zeros(all_ids.shape, bool)
+
+    def collect(a, res):
+        bits, s_at, p_at = (np.asarray(r) for r in res)
+        qi, rows = _bit_rows(bits, a)
+        np.add.at(n_found, qi, 1)
+        if n_found.max() > MAX_CANDIDATES:
+            raise RuntimeError(f"query {int(n_found.argmax())}: more than "
+                               f"{MAX_CANDIDATES} candidate rows")
+        for i in np.unique(qi):
+            cand_rows[i].append(rows[qi == i])
+        sure_at[...] |= s_at
+        possible_at[...] |= p_at
+
+    def last(device, a, b, xc):
+        out = chunk_out(device, xc)[0]
+        return _candidates(out, b - a, a, jax.device_put(t, device),
+                           None if thr is None else jax.device_put(thr, device),
+                           jax.device_put(ids_dev, device))
+    _pass(source, bounds, size, last, collect)
+
     readings = []
     n_cand = 0
-    rows_needed = [np.union1d(np.flatnonzero(cand[i]),
-                              np.concatenate([np.asarray(ids[i], np.int64) for _, ids in answers]))
+    rows_needed = [np.union1d(np.concatenate(cand_rows[i] or [np.zeros(0, np.int64)]),
+                              all_ids[i][(all_ids[i] >= 0) & (all_ids[i] < n)])
                    for i in range(nq)]
     for i in range(nq):
         if len(rows_needed[i]) > MAX_CANDIDATES:
             raise RuntimeError(f"query {i}: {len(rows_needed[i])} candidate rows")
+    fetch = np.unique(np.concatenate(rows_needed))
+    x_fetch = source.take(fetch)
+    sure_rows = [np.union1d(np.concatenate(cand_rows[i] or [np.zeros(0, np.int64)]),
+                            all_ids[i][sure_at[i]]) for i in range(nq)]
+    col = 0
     for which, (scores, ids) in enumerate(answers):
         worst_s = worst_r = 0.0
         structural = 0
@@ -451,27 +630,28 @@ def check(x_dev, q: np.ndarray, got_scores: np.ndarray, got_ids: np.ndarray,
             sc_i = np.asarray(scores[i], np.float64)
             if (ids_i.min() < 0 or ids_i.max() >= n or len(set(ids_i)) != k
                     or not np.all(np.isfinite(sc_i)) or np.any(np.diff(sc_i) > 0)
-                    or not possible_h[i, ids_i].all()):
+                    or not possible_at[i, col:col + k].all()):
                 structural += 1
                 continue
             rows = rows_needed[i]
             if which == 0:
                 n_cand += len(rows)
-            lo, hi, dn = exact_bounds(q_rot[i], np.asarray(x_dev[jnp.asarray(rows)]), sem)
+            lo, hi, dn = exact_bounds(q_rot[i], x_fetch[np.searchsorted(fetch, rows)], sem)
             qn = np.linalg.norm(q_rot[i])
             size = qn if sem.metric == "cosine" else qn * dn.max() + (
                 0.5 * dn.max() ** 2 if sem.metric == "l2" else 0.0)
             pos = np.searchsorted(rows, ids_i)
             err = np.maximum(np.maximum(lo[pos] - sc_i, sc_i - hi[pos]), 0.0)
-            sure_lo = lo[sure_h[i, rows]]
-            t = np.sort(sure_lo)[-k] if len(sure_lo) >= k else -np.inf
-            rank = np.maximum(t - hi[pos], 0.0)
+            sure_lo = lo[np.isin(rows, sure_rows[i])]
+            t_i = np.sort(sure_lo)[-k] if len(sure_lo) >= k else -np.inf
+            rank = np.maximum(t_i - hi[pos], 0.0)
             worst_s = max(worst_s, float(err.max() / size))
             errs.append(err / size)
             worst_r = max(worst_r, float(rank.max() / size))
         gap = STRUCTURAL if structural else max(worst_s, worst_r)
         mean = float(np.mean(np.concatenate(errs))) if errs else STRUCTURAL
         readings.append((gap, worst_s, worst_r, structural, mean))
+        col += k
     recall = float(np.mean([len(set(np.asarray(got_ids[i], np.int64)) & set(exact_top[i])) / k
                             for i in range(nq)]))
     gap, s_err, r_err, structural, mean = readings[0]

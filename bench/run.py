@@ -5,8 +5,9 @@
 From the root of a checkout.  A run finds the cell in BENCHMARK.json, checks
 that JAX sees a TPU with the cell's chips (else it exits 2 before any work),
 keeps JAX's compilation cache in ``<checkout>/.jax_cache``, builds the cell's
-index from the seed through ``MonaVec`` and a ``TenantRegistry``, warms the
-one shape its traffic uses, and then, for ``--seconds``, calls the bound
+index from the seed through ``MonaVec`` (or, for a block-keyed corpus,
+``ShardedMonaVec`` over the cell's chips) and a ``TenantRegistry``, warms
+the one shape its traffic uses, and then, for ``--seconds``, calls the bound
 searcher in a closed loop.  ``--trace 1`` runs a shorter window (the traffic
 mix's ``trace_seconds``) under the profiler and reports the per-layer
 metrics instead of the end-to-end ones.
@@ -64,6 +65,12 @@ def memory(chips: int, key: str = "peak_bytes_in_use") -> int:
     return int(max((d.memory_stats() or {}).get(key, 0) for d in jax.devices()[:chips]))
 
 
+def host_peak_rss() -> int:
+    """The process's peak resident set on the host, in bytes."""
+    import resource
+    return resource.getrusage(resource.RUSAGE_SELF).ru_maxrss * 1024
+
+
 class Cell:
     """One cell made ready to measure: the index built and registered, its
     query pool drawn, its one shape warmed."""
@@ -79,24 +86,25 @@ class Cell:
         self.mix = manifest.traffic(self.man, root, self.wl["traffic"])
         self.seed = seed
         cfg, mix = self.cfg, self.mix
-        x = self.corpus()
-        x.block_until_ready()
+        self.chips = int(self.wl["chips"])
+        rows = self.corpus()
+        rows.block(0).block_until_ready()   # a block-keyed corpus: its first block
         lap("corpus")
         self.calibration = None
         std = None
         if cfg.get("fit"):
-            self.calibration = np.asarray(datagen.corpus(
+            self.calibration = datagen.sample(
                 cfg["data"], cfg["fit"]["calibration_seed"],
-                cfg["fit"]["sample_rows"], cfg["dim"]))
+                cfg["fit"]["sample_rows"], cfg["dim"])
             std = system.fit(self.calibration)
         lap("fit")
-        self.reg = system.build(cfg, x, std)
+        self.reg = system.build(cfg, rows, std)
         lap("build")
         self.k, self.batch = int(mix["k"]), int(mix["batch"])
         self.search = system.searcher(self.reg, self.k, mix["knobs"])
-        pool = datagen.query_pool(x, seed, self.batch * int(mix["pool_batches"]),
+        pool = datagen.query_pool(rows, seed, self.batch * int(mix["pool_batches"]),
                                   cfg["data"]["query_noise"])
-        del x       # the deployment holds its codes, not the raw corpus
+        del rows    # the deployment holds its codes, not the raw corpus
         self.batches = [pool[i:i + self.batch] for i in range(0, len(pool), self.batch)]
         lap("queries")
         for _ in range(2):
@@ -107,9 +115,13 @@ class Cell:
         self.bucket = max(8, 1 << (self.batch - 1).bit_length())
 
     def corpus(self):
-        """The raw corpus, made anew from the seed (on the device)."""
+        """The raw corpus, made anew from the seed (on the device): whole,
+        or block by block over the cell's chips."""
+        import jax
+
         from bench import datagen
-        return datagen.corpus(self.cfg["data"], self.seed, self.cfg["n"], self.cfg["dim"])
+        return datagen.Rows(self.cfg["data"], self.seed, self.cfg["n"], self.cfg["dim"],
+                            jax.devices()[:self.chips])
 
     def free_program(self) -> None:
         del self.search, self.reg
@@ -228,6 +240,7 @@ def run(args, root: Path = ROOT, require_tpu: bool = True) -> dict:
                               v[1] - stages0.get(s, (0.0, 0))[1])
                           for s, v in stages1.items()},
                "cell": {"bucket": cell.bucket, "n": cell.cfg["n"], "k": cell.k,
+                        "rows_per_chip": cell.cfg["n"] // cell.chips,
                         "m": cell.m, "d_pad": 1 << (cell.cfg["dim"] - 1).bit_length()}}
         for m in manifest.metrics_for(man, args.workload, "per_layer"):
             v = manifest.reader(man, root, m["name"])(ctx)
@@ -240,7 +253,8 @@ def run(args, root: Path = ROOT, require_tpu: bool = True) -> dict:
     record = {"workload": args.workload, "seed": args.seed, "trace": args.trace,
               "setup_s": setup_s, "setup_phases_s": phases,
               "bytes_in_use_after_window": in_use,
-              "reference_s": ref_s, "retraces_in_window": retraces,
+              "reference_s": ref_s, "host_peak_rss_bytes": host_peak_rss(),
+              "retraces_in_window": retraces,
               "window": stats, "latencies_ms": [1e3 * (c.t1 - c.t0) for c in calls],
               "verdict": None if verdict is None else verdict._asdict(),
               "result": result}

@@ -17,15 +17,16 @@ DATA = {"n_clusters": 8, "center": "uniform", "center_scale": 0.1, "noise": 0.03
 
 
 def _case(metric, coarse):
-    x = datagen.corpus(DATA, 7, 700, CFG["dim"])
+    rows = datagen.Rows(DATA, 7, 700, CFG["dim"])
+    x = rows.block(0)
     calib = np.asarray(datagen.corpus(DATA, 8, 512, CFG["dim"])) if metric == "l2" else None
     std = MonaVec.fit(calib) if calib is not None else None
     idx = MonaVec.build(x, metric=metric, seed=CFG["rotation_seed"], std=std, coarse=coarse)
-    q = datagen.query_pool(x, 9, 16, 0.01)
+    q = datagen.query_pool(rows, 9, 16, 0.01)
     knobs = {"rescore_mult": 4} if coarse else {}
     scores, ids = idx.search(q, K, use_kernel=True, interpret=True, **knobs)
     sem = reference.semantics(dict(CFG, metric=metric, fit=calib is not None), calib)
-    return x, q, scores, ids.astype(np.int64), sem, (4 * K if coarse else None), idx
+    return rows, q, scores, ids.astype(np.int64), sem, (4 * K if coarse else None), idx
 
 
 CASES = [("cosine", None), ("l2", None), ("l2", "crumb")]
@@ -58,7 +59,7 @@ def test_an_altered_answer_is_caught(metric, coarse):
 @pytest.mark.parametrize("metric", ["cosine", "l2"])
 def test_reference_codes_are_the_programs_off_the_boundaries(metric):
     x, _, _, _, sem, _, idx = _case(metric, None)
-    xh = np.asarray(x)
+    xh = np.asarray(x.block(0))
     y = reference.rotate64(xh, sem)
     code, _, amb = reference.code_options(
         y, np.linalg.norm(reference.prepare64(xh, sem), axis=1),

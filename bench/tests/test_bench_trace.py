@@ -1,6 +1,7 @@
 """Trace reduction on a hand-built trace, and the kernels' least times."""
 
 import importlib.util
+import json
 
 import pytest
 
@@ -83,6 +84,22 @@ def test_coarse_least_time_at_known_shapes():
 def test_roofline_reader_share():
     m = _reader("nibble_dot_roofline")
     ctx = {"trace": TRACE, "peaks": PEAKS,
-           "cell": {"bucket": 256, "n": 45056, "d_pad": 1024}}
+           "cell": {"bucket": 256, "n": 45056, "rows_per_chip": 45056, "d_pad": 1024}}
     least = m.least_seconds(256, 45056, 1024, PEAKS)
     assert m.read(ctx) == pytest.approx(100 * 2 * least / 300e-9)
+
+
+@pytest.mark.parametrize("name", ["nibble_dot_roofline", "crumb_dot_roofline"])
+def test_roofline_counts_the_rows_one_event_scans(name):
+    """Sharded over four chips, each chip's event scans a quarter of the
+    corpus: the share is that of one chip's rows, not of all n."""
+    m = _reader(name)
+    kernel = {"nibble_dot_roofline": "nibble_dot_raw",
+              "crumb_dot_roofline": "crumb_affinity_raw"}[name]
+    trace = json.loads(json.dumps(TRACE).replace("nibble_dot_raw", kernel))
+    one = {"bucket": 256, "n": 45056, "rows_per_chip": 45056, "d_pad": 1024}
+    four = dict(one, n=4 * 45056)
+    assert m.read({"trace": trace, "peaks": PEAKS, "cell": four}) == pytest.approx(
+        m.read({"trace": trace, "peaks": PEAKS, "cell": one}))
+    assert m.read({"trace": trace, "peaks": PEAKS, "cell": dict(four, rows_per_chip=4 * 45056)}) \
+        == pytest.approx(4 * m.read({"trace": trace, "peaks": PEAKS, "cell": one}), rel=1e-3)
